@@ -62,6 +62,10 @@ def main() -> None:
         args = [a for a in args if a != "--smoke"]
         os.environ.setdefault("BENCH_SCALE", "0.25")
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         cluster_scaling,
         grad_compression,

@@ -816,7 +816,6 @@ def make_protocol_runner(protocol: str, cfg: ProtocolConfig, mesh: jax.sharding.
     jitted callable (and its traces) instead of paying a retrace each.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     cfg = cfg.resolved()
     cached = _RUNNER_CACHE.get((protocol, cfg, mesh))
@@ -881,12 +880,12 @@ def make_protocol_runner(protocol: str, cfg: ProtocolConfig, mesh: jax.sharding.
     specs = _state_specs(state0)
 
     step = jax.jit(
-        shard_map(
+        jax.shard_map(
             _inner,
             mesh=mesh,
             in_specs=(specs, data_spec),
             out_specs=specs,
-            check_rep=False,
+            check_vma=False,
         )
     )
     _RUNNER_CACHE[(protocol, cfg, mesh)] = (state0, step)
@@ -950,7 +949,6 @@ def make_packed_runner(
     (each jit retraces per distinct (T, n) launch shape).
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     cfg = cfg.resolved()
     if protocol not in PACKABLE_PROTOCOLS:
@@ -994,12 +992,12 @@ def make_packed_runner(
         return type(new)(**{n: rebatch(n, getattr(new, n)) for n in new._fields})
 
     specs = _specs(one)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         _inner,
         mesh=mesh,
         in_specs=(specs, P(None, cfg.axis, None)),
         out_specs=specs,
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

@@ -9,7 +9,8 @@ for both kernels.
 Pallas kernel on a real accelerator and to the jit'd XLA reference wherever
 the kernel would run in interpret mode (interpreted Pallas loses to XLA on
 CPU); ``"pallas"`` / ``"xla"`` force one implementation.  Both paths agree
-to 1e-5 (regression-tested).
+to 1e-5 on CPU (regression-tested) and bit for bit on a TPU v5e, where
+both multiply f32 in one bf16 pass (see ``kernels.ops``).
 """
 from __future__ import annotations
 

@@ -1,7 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel (the ``ref.py`` contracts).
 
 Tests sweep shapes/dtypes and assert the kernels (interpret=True on CPU)
-match these to tight tolerances.
+match these to tight tolerances.  Products take f32 inputs and accumulate
+in f32 at default precision, which on a TPU is one bf16 pass — the same
+pass the Pallas kernels make there (see ``kernels.ops``).
 """
 from __future__ import annotations
 
@@ -10,7 +12,7 @@ import jax.numpy as jnp
 
 
 def ref_fd_gram(b: jax.Array) -> jax.Array:
-    """FD Gram product ``G = B @ B.T`` in f32.  b: (L, d) -> (L, L)."""
+    """FD Gram product ``G = B @ B.T``, f32 out.  b: (L, d) -> (L, L)."""
     b32 = b.astype(jnp.float32)
     return jnp.matmul(b32, b32.T, preferred_element_type=jnp.float32)
 

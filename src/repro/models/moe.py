@@ -98,7 +98,6 @@ def _moe_shard_map(cfg, params: dict, x: jax.Array, ctx) -> jax.Array:
     all-reduces.
     """
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     e_v, k_v, ff_v, _ = _eff_dims(cfg)
@@ -144,7 +143,7 @@ def _moe_shard_map(cfg, params: dict, x: jax.Array, ctx) -> jax.Array:
         out = lax.psum(partial, mdl)
         return out.reshape(xb.shape)
 
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=ctx["mesh"],
         in_specs=(
@@ -155,7 +154,7 @@ def _moe_shard_map(cfg, params: dict, x: jax.Array, ctx) -> jax.Array:
             P(mdl, None, None),
         ),
         out_specs=P(dp, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"], params["w_down"])
 
 

@@ -119,7 +119,6 @@ def make_compressed_train_step(
     ``compress_axis`` (the slow inter-pod/DCN link).  This is the paper's own
     topology: pods = sites, the cross-pod link = the coordinator channel.
     """
-    from jax.experimental.shard_map import shard_map
 
     ccfg = tcfg.grad_compression or FDCompressConfig()
     dp = tuple(axes) if axes is not None else data_axes(mesh)
@@ -157,12 +156,12 @@ def make_compressed_train_step(
 
     # Spec prefixes: state/metrics replicated, batch sharded over DP.
     step = jax.jit(
-        shard_map(
+        jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(), {"tokens": P(dp, None)}),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
     )
     return step
