@@ -61,7 +61,13 @@ def mg_init(k: int) -> MGState:
 
 
 def mg_update(state: MGState, key: jax.Array, w: jax.Array) -> MGState:
-    """Absorb one (element, weight) pair.  Fully branch-free / jit-able."""
+    """Absorb one (element, weight) pair.  Fully branch-free / jit-able.
+
+    A zero-weight item takes no slot, and a counter the decrement drives
+    to zero frees its slot, so every live key has a positive count (the
+    representation ``mg_merge`` produces, which makes the empty summary
+    a bit-identical two-sided identity).
+    """
     keys, counts = state.keys, state.counts
     key = key.astype(jnp.int32)
     w = w.astype(jnp.float32)
@@ -69,7 +75,8 @@ def mg_update(state: MGState, key: jax.Array, w: jax.Array) -> MGState:
     hit = keys == key
     any_hit = jnp.any(hit)
     empty = keys == EMPTY
-    any_empty = jnp.any(empty)
+    # A zero-weight item takes no empty slot: it falls to case 3 with delta 0.
+    any_empty = jnp.any(empty) & (w > 0.0)
     first_empty = jnp.argmax(empty)
 
     # Case 1: existing counter.
@@ -81,10 +88,11 @@ def mg_update(state: MGState, key: jax.Array, w: jax.Array) -> MGState:
     min_c = jnp.min(counts)
     delta = jnp.minimum(min_c, w)
     counts_dec = jnp.maximum(counts - delta, 0.0)
+    keys_dec = jnp.where(counts_dec > 0.0, keys, EMPTY)
     w_left = w - delta
     freed = jnp.argmin(counts)  # a slot that hit zero when delta == min_c
     take_slot = w_left > 0.0
-    keys_dec = jnp.where(take_slot, keys.at[freed].set(key), keys)
+    keys_dec = jnp.where(take_slot, keys_dec.at[freed].set(key), keys_dec)
     counts_dec = jnp.where(take_slot, counts_dec.at[freed].set(w_left), counts_dec)
     # shrink witness: every element's estimate dropped by at most delta
     # (the replaced slot loses min_c, the incoming item loses delta).
@@ -95,12 +103,16 @@ def mg_update(state: MGState, key: jax.Array, w: jax.Array) -> MGState:
     return MGState(new_keys, new_counts, state.weight + w, new_shrink)
 
 
-def mg_update_stream(state: MGState, keys: jax.Array, weights: jax.Array) -> MGState:
-    """Scan ``mg_update`` over a (keys, weights) batch."""
-    def body(st, kw):
-        return mg_update(st, kw[0], kw[1]), None
+def _mg_scan_body(st: MGState, kw):
+    return mg_update(st, kw[0], kw[1]), None
 
-    state, _ = jax.lax.scan(body, state, (keys.astype(jnp.int32), weights.astype(jnp.float32)))
+
+@jax.jit
+def mg_update_stream(state: MGState, keys: jax.Array, weights: jax.Array) -> MGState:
+    """Scan ``mg_update`` over a (keys, weights) batch (compiled once per shape)."""
+    state, _ = jax.lax.scan(
+        _mg_scan_body, state, (keys.astype(jnp.int32), weights.astype(jnp.float32))
+    )
     return state
 
 

@@ -34,11 +34,15 @@ counterpart.  This module supplies the third workload kind's math:
 from __future__ import annotations
 
 import bisect
+import functools
 import math
 from dataclasses import dataclass
 from typing import NamedTuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 __all__ = [
     "QUERY_RANK",
@@ -454,8 +458,6 @@ class QuantState(NamedTuple):
 
 def quant_init(cap: int) -> QuantState:
     """The empty summary at capacity ``cap`` (merge identity)."""
-    import jax.numpy as jnp
-
     return QuantState(
         values=jnp.full((cap,), jnp.inf, jnp.float32),
         g=jnp.zeros((cap,), jnp.float32),
@@ -465,7 +467,19 @@ def quant_init(cap: int) -> QuantState:
     )
 
 
-def _quant_pack(v, rmin, rmax, wv, live, thresh, cap, weight):
+def _order_key(v):
+    """Exact int32 sort key of f32 values: orders like the floats, with
+    ``-0.0`` equal to ``0.0``.  XLA compares subnormals as zero, so sorting
+    and testing equality on the floats themselves would merge a subnormal
+    value into 0 and certify a rank the host (and the exact stream) does
+    not see; the key keeps every distinct value distinct.
+    """
+    bits = lax.bitcast_convert_type(v, jnp.int32)
+    bits = jnp.where(bits == jnp.iinfo(jnp.int32).min, 0, bits)  # -0.0
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _quant_pack(v, key, rmin, rmax, wv, live, thresh, cap, weight):
     """Greedy GK compress of sorted interval tuples into ``cap`` slots.
 
     Folds tuple i forward into i+1 while the merged band stays within
@@ -476,9 +490,6 @@ def _quant_pack(v, rmin, rmax, wv, live, thresh, cap, weight):
     ``cap``, the overflow keeps folding into the last slot — still
     interval-sound, just wider bands near the maximum.
     """
-    import jax
-    import jax.numpy as jnp
-
     n = v.shape[0]
     g = jnp.maximum(rmin - jnp.concatenate([jnp.zeros(1, rmin.dtype), rmin[:-1]]), 0.0)
     d = jnp.maximum(rmax - rmin, 0.0)
@@ -489,7 +500,7 @@ def _quant_pack(v, rmin, rmax, wv, live, thresh, cap, weight):
         acc = acc + jnp.where(live_i, g[i], 0.0)
         nxt = jnp.minimum(i + 1, n - 1)
         has_next = (i + 1 < n) & live[nxt]
-        same_value = has_next & (v[nxt] == v[i])
+        same_value = has_next & (key[nxt] == key[i])
         fold = same_value | (
             has_next & (acc + g[nxt] + d[nxt] - wv[nxt] <= thresh) & (count > 0)
         )
@@ -516,21 +527,23 @@ def _quant_pack(v, rmin, rmax, wv, live, thresh, cap, weight):
         jnp.zeros((), jnp.float32),
         jnp.zeros((), jnp.float32),
     )
-    out_v, out_g, out_d, out_wv, _, _, _ = jax.lax.fori_loop(0, n, body, init)
+    out_v, out_g, out_d, out_wv, _, _, _ = lax.fori_loop(0, n, body, init)
     return QuantState(out_v, out_g, out_d, out_wv, weight.astype(jnp.float32))
 
 
+@functools.partial(jax.jit, static_argnames=("eps", "cap"))
 def quant_merge(a: QuantState, b: QuantState, eps: float, cap: int) -> QuantState:
     """Merge two jit-state summaries and compress to ``cap`` (band <= eps*W).
 
     The vectorized twin of ``QuantileSummary.merge``: sort the union,
     rebuild every tuple's rank interval as its own interval plus the
     other summary's certified interval at that value, then greedy-pack.
+    Compiled once per (shape, ``eps``, ``cap``).
     """
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
+    return _quant_merge(a, b, eps, cap)
 
+
+def _quant_merge(a: QuantState, b: QuantState, eps: float, cap: int) -> QuantState:
     v = jnp.concatenate([a.values, b.values])
     g = jnp.concatenate([a.g, b.g])
     d = jnp.concatenate([a.delta, b.delta])
@@ -538,8 +551,10 @@ def quant_merge(a: QuantState, b: QuantState, eps: float, cap: int) -> QuantStat
     la = a.values.shape[0]
     n = v.shape[0]
     is_a = jnp.arange(n) < la
-    order = jnp.argsort(v, stable=True)  # ties: A entries first
-    v, g, d, wv, is_a = v[order], g[order], d[order], wv[order], is_a[order]
+    key = _order_key(v)
+    order = jnp.argsort(key, stable=True)  # ties: A entries first
+    v, key, g, d, wv = v[order], key[order], g[order], d[order], wv[order]
+    is_a = is_a[order]
     live = jnp.isfinite(v)
 
     cum_a = jnp.cumsum(jnp.where(is_a, g, 0.0))
@@ -556,10 +571,16 @@ def quant_merge(a: QuantState, b: QuantState, eps: float, cap: int) -> QuantStat
 
     next_a = jnp.concatenate([suffix_min(pos_a)[1:], jnp.array([n])])
     next_b = jnp.concatenate([suffix_min(pos_b)[1:], jnp.array([n])])
-    n_other = jnp.where(is_a, next_b, next_a)
+    # The other side's certified upper bound at v is read from its first
+    # tuple at value >= v.  Each side's values are distinct, so an equal
+    # value on both sides is an adjacent (A, B) pair: the B entry reads
+    # the A tuple just before it, not the next A tuple by index.
+    prev = jnp.maximum(idx - 1, 0)
+    tie = live & (idx > 0) & (key[prev] == key) & (is_a[prev] != is_a)
+    n_other = jnp.where(tie, prev, jnp.where(is_a, next_b, next_a))
     w_other = jnp.where(is_a, b.weight, a.weight)
     safe = jnp.clip(n_other, 0, n - 1)
-    up = own_cum[safe] + d[safe] - wv[safe] * (v[safe] > v)
+    up = own_cum[safe] + d[safe] - wv[safe] * (key[safe] > key)
     upper_other = jnp.where(n_other < n, up, w_other)
     rmax = own_cum + d + upper_other
 
@@ -567,31 +588,34 @@ def quant_merge(a: QuantState, b: QuantState, eps: float, cap: int) -> QuantStat
     rmax = jnp.maximum(rmax, rmin)
     weight = a.weight + b.weight
     thresh = jnp.float32(2.0 * eps) * weight
-    return _quant_pack(v, rmin, rmax, wv, live, thresh, cap, weight)
+    return _quant_pack(v, key, rmin, rmax, wv, live, thresh, cap, weight)
 
 
 def quant_insert(state: QuantState, values, weights, eps: float) -> QuantState:
     """Absorb a weighted batch: dedup exact values, merge as an exact summary."""
-    import jax.numpy as jnp
-
-    cap = state.values.shape[0]
     values = jnp.asarray(values, jnp.float32).ravel()
     weights = jnp.asarray(weights, jnp.float32).ravel()
-    n = values.shape[0]
-    if n == 0:  # static shape: nothing to absorb
+    if values.shape[0] == 0:  # static shape: nothing to absorb
         return state
-    order = jnp.argsort(values)
-    vs, ws = values[order], weights[order]
+    return _quant_absorb(state, values, weights, eps)
+
+
+@functools.partial(jax.jit, static_argnames=("eps",))
+def _quant_absorb(state: QuantState, values, weights, eps: float) -> QuantState:
+    n = values.shape[0]
+    key = _order_key(values)
+    order = jnp.argsort(key)
+    vs, ks, ws = values[order], key[order], weights[order]
     seg = jnp.cumsum(
-        jnp.concatenate([jnp.zeros(1, jnp.int32), (vs[1:] != vs[:-1]).astype(jnp.int32)])
+        jnp.concatenate([jnp.zeros(1, jnp.int32), (ks[1:] != ks[:-1]).astype(jnp.int32)])
     )
     g = jnp.zeros((n,), jnp.float32).at[seg].add(ws)
-    v = jnp.full((n,), jnp.inf, jnp.float32).at[seg].min(vs)
+    v = jnp.full((n,), jnp.inf, jnp.float32).at[seg].set(vs)  # a segment's values share one key
     v = jnp.where(g > 0, v, jnp.inf)  # drop zero-weight slots and pad tails
     batch = QuantState(
         values=v, g=g, delta=jnp.zeros_like(g), wv=g, weight=jnp.sum(ws)
     )
-    return quant_merge(state, batch, eps, cap)
+    return _quant_merge(state, batch, eps, state.values.shape[0])
 
 
 def quant_table(state: QuantState) -> np.ndarray:

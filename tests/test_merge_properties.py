@@ -115,9 +115,18 @@ def test_fd_merge_identity_absorption():
     run_property(check, given=_fd_given, cases=_fd_cases(15), max_examples=15)
 
 
+# All-zero inputs: zero rows are not counted in ``n_seen``.
+_FD_ZERO_ROWS = {
+    "a": np.zeros((5, 4), np.float32),
+    "b": np.zeros((5, 4), np.float32),
+    "l": _FD_L,
+}
+
+
 def test_fd_merge_of_splits_matches_stream_envelope():
     """Split-then-merge conserves mass/count exactly and keeps the FD
-    guarantee ``0 <= ||Ax||^2 - ||Bx||^2 <= delta_sum`` of the full stream."""
+    guarantee ``0 <= ||Ax||^2 - ||Bx||^2 <= delta_sum`` of the full stream.
+    ``n_seen`` counts the non-zero rows (``fd_update``'s contract)."""
 
     def check(a, b, l):
         d = a.shape[1]
@@ -127,7 +136,8 @@ def test_fd_merge_of_splits_matches_stream_envelope():
             fd_update_stream(fd_init(l, d), jnp.asarray(b)),
         )
         frob = float(np.sum(full.astype(np.float64) ** 2))
-        assert int(merged.n_seen) == full.shape[0]
+        n_nonzero = int(np.sum(np.sum(full.astype(np.float32) ** 2, axis=1) > 0))
+        assert int(merged.n_seen) == n_nonzero
         assert abs(float(merged.frob) - frob) <= 1e-3 * frob + 1e-4
         x = jnp.asarray(np.ones(d, np.float32) / np.sqrt(d))
         ax = float(np.sum((full.astype(np.float64) @ np.asarray(x)) ** 2))
@@ -136,7 +146,13 @@ def test_fd_merge_of_splits_matches_stream_envelope():
         assert ax - bx >= -slack  # underestimate (shrink only subtracts)
         assert ax - bx <= float(merged.delta_sum) + slack  # certified deficit
 
-    run_property(check, given=_fd_given, cases=_fd_cases(25), max_examples=25)
+    run_property(
+        check,
+        given=_fd_given,
+        cases=_fd_cases(25),
+        max_examples=25,
+        examples=[_FD_ZERO_ROWS],
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -198,6 +214,18 @@ def test_mg_merge_commutes_up_to_served_answer():
     run_property(check, given=_mg_given, cases=_mg_cases(30), max_examples=30)
 
 
+# Key 6 (weight 2) decrements six unit counters to zero at once; the
+# zeroed keys must free their slots, or the merge (which drops them) is
+# not an identity.
+_MG_ZEROED_COUNTERS = {
+    "ka": [0, 1, 2, 3, 4, 5, 6] + [6] * 33,
+    "wa": [1.0] * 6 + [2.0] + [1.0] * 33,
+    "kb": list(range(25)) + list(range(15)),
+    "wb": [1.0] * 40,
+    "k": 6,
+}
+
+
 def test_mg_merge_identity_absorption():
     """The empty MG summary is a two-sided identity, bit-identically."""
 
@@ -209,7 +237,13 @@ def test_mg_merge_identity_absorption():
             assert float(merged.shrink) == float(sa.shrink)
             assert mg_items(merged) == mg_items(sa)
 
-    run_property(check, given=_mg_given, cases=_mg_cases(20), max_examples=20)
+    run_property(
+        check,
+        given=_mg_given,
+        cases=_mg_cases(20),
+        max_examples=20,
+        examples=[_MG_ZEROED_COUNTERS],
+    )
 
 
 def test_mg_merge_of_splits_keeps_the_mg_guarantee():
@@ -309,6 +343,31 @@ def test_quant_merge_identity_absorption():
     run_property(check, given=_quant_given, cases=_quant_cases(20), max_examples=20)
 
 
+# A value (0) on both sides: the merged tuple must read each side's
+# exact rank at 0, not the other side's next tuple.
+_QU_SHARED_VALUE = {
+    "va": [0.0] * 9 + [
+        6.0, 1.899999976158142, 1950.5760498046875, 3704.3876953125,
+        8710.4970703125, 9420.0185546875, 0.3333333432674408, -2.0, -1.5,
+        -1409.4228515625, -1410.2528076171875, -3364.281494140625,
+        -3674.55029296875, -4905.302734375, -4955.45166015625,
+        -7325.44384765625, -8999.662109375, -10000.0, -0.5,
+        -2.4966420197412385e-19, -2.6015094779802514e-36,
+    ],
+    "vb": [0.0] * 21 + [
+        -9999.0, -194.7556915283203, -316.2803649902344, -3258.142822265625,
+        -6153.875, -7100.24072265625, -2.0, -2.0, -1.0,
+    ],
+    "eps": 0.1,
+}
+# A subnormal value, which XLA compares as equal to 0.
+_QU_SUBNORMAL = {
+    "va": [0.0] * 30,
+    "vb": [0.0] * 29 + [6.58610278232664e-44],
+    "eps": 0.1,
+}
+
+
 def test_quant_merge_of_splits_keeps_eps_band():
     """Split-then-merge conserves weight exactly and serves every probe
     within its certified band of the exact ranks — the paper's guarantee."""
@@ -328,7 +387,13 @@ def test_quant_merge_of_splits_keeps_eps_band():
         exact = exact_ranks(full, np.ones(full.shape[0], np.float32), probes)
         assert np.max(np.abs(served - exact)) <= band + 1e-3 * W + 1e-4
 
-    run_property(check, given=_quant_given, cases=_quant_cases(25), max_examples=25)
+    run_property(
+        check,
+        given=_quant_given,
+        cases=_quant_cases(25),
+        max_examples=25,
+        examples=[_QU_SHARED_VALUE, _QU_SUBNORMAL],
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -278,6 +278,50 @@ def test_shuffled_arrival_within_lateness_is_byte_identical(kind):
     )
 
 
+@pytest.mark.parametrize("kind", ("hh", "quantile"))
+def test_second_windowed_instance_compiles_nothing(kind):
+    """The HH and quantile state algebra is compiled once per static
+    configuration: a second protocol of the same spec, fed the same batch
+    shapes, adds no entry to any jitted entry point's cache."""
+    from repro.core import hh, quantiles
+
+    entry_points = {
+        "hh": (hh.mg_update_stream,),
+        "quantile": (quantiles.quant_merge, quantiles._quant_absorb),
+    }[kind]
+
+    def run_one():
+        rng = np.random.default_rng(0)
+        proto = _make(kind, "event", "win", window=WINDOW, buckets=BUCKETS)
+        for t in range(29):
+            proto.step(_batch(kind, rng), ts=float(t))
+        # the served answer folds the live buckets with the kind's merge
+        answer = proto.estimates() if kind == "hh" else proto.rank([0.0])
+        return proto.state_payload(), answer
+
+    compiles = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    first = run_one()
+    sizes = [fn._cache_size() for fn in entry_points]
+    assert all(n > 0 for n in sizes)  # the first instance went through them
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        second = run_one()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert [fn._cache_size() for fn in entry_points] == sizes
+    assert compiles == []
+    (arrays_1, _), answer_1 = first
+    (arrays_2, _), answer_2 = second
+    for k in arrays_1:
+        np.testing.assert_array_equal(np.asarray(arrays_1[k]), np.asarray(arrays_2[k]))
+    np.testing.assert_equal(answer_1, answer_2)
+
+
 @pytest.mark.parametrize("kind", KINDS)
 def test_late_rows_are_shed_counted_and_never_applied(kind):
     """A batch older than the watermark raises ``LateRowError`` carrying
