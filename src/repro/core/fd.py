@@ -8,7 +8,10 @@ Two implementations, cross-validated in tests:
   most ``l`` non-zero rows each time the buffer fills.  The shrink is computed
   with the Gram trick (``G = B @ B.T`` is ``2l x 2l``; ``eigh`` on it instead
   of an SVD of ``2l x d``), whose two matmul hot-spots map onto the Pallas
-  kernels ``fd_gram`` / ``fd_project``.
+  kernels ``fd_gram`` / ``fd_project``.  ``fd_update_stream`` absorbs a
+  batch either by that chain of shrinks, one per l-row chunk, or by one
+  shrink of the whole batch through the ``d x d`` covariance; ``fd_route``
+  picks between them from the shapes alone.
 
 * ``FDSketch`` — a plain-numpy, item-at-a-time oracle with the exact
   conditional-shrink semantics of the paper; used by the event-driven
@@ -40,17 +43,19 @@ __all__ = [
     "fd_query",
     "fd_matrix",
     "fd_shrink",
+    "fd_route",
     "jit_cache_stats",
     "FDSketch",
 ]
 
 
-# Jitted FD callables, keyed on (op, l, d, dtype, use_pallas[, n_chunks]).
+# Jitted FD callables, keyed on (op[, route], l, d, dtype, use_pallas[, size]).
 # Per-tenant ingest used to build a fresh trace per tracker instance for
 # identical shapes; the cache makes the T-th tenant a dict hit.  ``misses``
 # is the retrace count pipeline ingest observability surfaces.
 _JIT_CACHE: dict = {}
 _JIT_STATS = {"hits": 0, "misses": 0}
+_ROUTES = {"covariance": 0, "chain": 0}
 
 
 def jit_cache_stats() -> dict:
@@ -58,9 +63,11 @@ def jit_cache_stats() -> dict:
 
     ``misses`` counts distinct (shape, dtype, backend) signatures traced —
     the retrace count; ``hits`` counts calls served by an already-built
-    callable.  Read by ``StreamingPipeline.stats()``.
+    callable; ``routes`` counts the ``fd_update_stream`` signatures traced
+    on each absorb route (``fd_route``).  Read by
+    ``StreamingPipeline.stats()``.
     """
-    return dict(_JIT_STATS)
+    return {**_JIT_STATS, "routes": dict(_ROUTES)}
 
 
 def _cached_jit(key: tuple, build):
@@ -194,23 +201,76 @@ def _fd_stream_impl(state: FDState, chunks: jax.Array, *, use_pallas: bool) -> F
     return state
 
 
-def fd_update_stream(state: FDState, rows: jax.Array, *, use_pallas: bool = False) -> FDState:
-    """Absorb ``(n, d)`` rows via a scan of l-row chunks (n padded up).
+def _fd_cover_impl(state: FDState, rows: jax.Array, *, use_pallas: bool) -> FDState:
+    """One shrink of the buffer [live sketch; rows] through its d x d covariance.
 
-    The jitted scan is cached on ``(l, d, dtype, use_pallas, n_chunks)`` —
-    per-tenant ingest of a common batch shape stops re-tracing per tenant.
+    Subtracting ``delta = lambda_{l+1}`` once from a buffer of any size
+    removes at least ``(l + 1) delta`` of mass, so ``delta_sum`` keeps the
+    bound ``||A||_F^2 / (l + 1)`` of the chain.  The new rows
+    ``sqrt(lambda_i - delta) v_i`` come straight from the eigenvectors:
+    orthogonal, sorted by norm, and zero past row l.
+    """
+    l, d = state.l, state.d
+    x = rows.astype(jnp.float32)
+    row_sq = jnp.sum(x**2, axis=1)
+    y = jnp.concatenate([state.buf[:l].astype(jnp.float32), x])
+    cov = _gram(y.T, use_pallas)  # y.T @ y, the Gram's precision
+    lam, v = jnp.linalg.eigh(cov)
+    lam = jnp.maximum(lam[::-1], 0.0)
+    v = v[:, ::-1]
+    delta = lam[l] if d > l else jnp.zeros((), jnp.float32)
+    k = min(l, d)
+    live = jnp.sqrt(jnp.maximum(lam[:k] - delta, 0.0))[:, None] * v[:, :k].T
+    return FDState(
+        buf=jnp.zeros_like(state.buf).at[:k].set(live.astype(state.buf.dtype)),
+        frob=state.frob + jnp.sum(row_sq),
+        delta_sum=state.delta_sum + delta,
+        n_seen=state.n_seen + jnp.sum(row_sq > 0).astype(jnp.int32),
+    )
+
+
+def fd_route(n: int, l: int, d: int) -> str:
+    """The absorb route ``fd_update_stream`` takes for ``n`` rows of width ``d``.
+
+    ``"covariance"`` is one ``d x d`` ``eigh`` for the whole batch;
+    ``"chain"`` is ``ceil(n / l)`` serial ``2l x 2l`` ``eigh``s.  Small
+    ``eigh``s cost their latency more than their size, so the covariance
+    route wins wherever its one ``eigh`` is no larger than the chain's
+    summed: ``d^2 <= ceil(n / l) (2l)^2``.
+    """
+    return "covariance" if d * d <= -(-n // l) * (2 * l) ** 2 else "chain"
+
+
+def _build_stream(route: str, impl, use_pallas: bool):
+    _ROUTES[route] += 1
+    return jax.jit(functools.partial(impl, use_pallas=use_pallas))
+
+
+def fd_update_stream(state: FDState, rows: jax.Array, *, use_pallas: bool = False) -> FDState:
+    """Absorb ``(n, d)`` rows on the route ``fd_route`` picks for the shapes.
+
+    The covariance route shrinks once for the whole batch; the chain route
+    scans l-row chunks (n padded up), one ``fd_shrink`` each.  Both keep
+    the sketch's guarantee, and leave its live rows orthogonal, sorted by
+    norm, with every row past l zero.  The jitted callable is cached on the
+    route and shapes — per-tenant ingest of a common batch shape stops
+    re-tracing per tenant.
     """
     l, d = state.l, state.d
     n = rows.shape[0]
-    n_chunks = -(-n // l)
-    pad = n_chunks * l - n
-    rows = jnp.pad(rows, ((0, pad), (0, 0)))
-    chunks = rows.reshape(n_chunks, l, d)
+    route = fd_route(n, l, d)
+    if route == "covariance":
+        impl = _fd_cover_impl
+        size = n
+    else:
+        impl = _fd_stream_impl
+        size = -(-n // l)
+        rows = jnp.pad(rows, ((0, size * l - n), (0, 0))).reshape(size, l, d)
     fn = _cached_jit(
-        ("stream", l, d, str(state.buf.dtype), bool(use_pallas), n_chunks),
-        lambda: jax.jit(functools.partial(_fd_stream_impl, use_pallas=use_pallas)),
+        ("stream", route, l, d, str(state.buf.dtype), bool(use_pallas), size),
+        lambda: _build_stream(route, impl, use_pallas),
     )
-    return fn(state, chunks)
+    return fn(state, rows)
 
 
 def fd_merge(a: FDState, b: FDState, *, use_pallas: bool = False) -> FDState:
@@ -288,6 +348,26 @@ class FDSketch:
             self.buf[self.fill : self.fill + take] = rows[i : i + take]
             self.fill += take
             i += take
+
+    def absorb_block(self, rows: np.ndarray) -> None:
+        """Absorb an (n, d) batch in one shrink of [sketch; batch].
+
+        The oracle of ``fd_update_stream``'s covariance route: the shrink
+        goes through the ``d x d`` covariance and subtracts its
+        ``(l+1)``-th eigenvalue (nothing when ``d <= l``) once.
+        """
+        y = np.concatenate([self.buf[: self.fill], rows])
+        lam, v = np.linalg.eigh(y.T @ y)
+        lam = np.maximum(lam[::-1], 0.0)
+        v = v[:, ::-1]
+        delta = lam[self.l] if self.d > self.l else 0.0
+        k = min(self.l, self.d)
+        self.buf = np.zeros_like(self.buf)
+        self.buf[:k] = np.sqrt(np.maximum(lam[:k] - delta, 0.0))[:, None] * v[:, :k].T
+        self.fill = self.l
+        self.frob += float(np.sum(rows * rows))
+        self.n_seen += rows.shape[0]
+        self.delta_sum += float(delta)
 
     def _shrink(self) -> None:
         g = self.buf @ self.buf.T

@@ -56,6 +56,7 @@ from typing import Iterable, NamedTuple
 import jax
 import numpy as np
 
+from repro.core.fd import jit_cache_stats
 from repro.core.windows import LateRowError, TimedRows
 from repro.obs import Observability, rehome_families
 from repro.query import QueryEngine, SketchStore
@@ -1550,10 +1551,12 @@ class StreamingPipeline:
         number of protocol super-steps actually dispatched),
         ``pack_occupancy`` (real-row fraction of packed launch slots;
         1.0 means no padding waste), ``retraces`` (distinct packed
-        launch shapes compiled), and ``restacks`` (packed launches that
+        launch shapes compiled), ``restacks`` (packed launches that
         had to restack member states instead of reusing the resident
-        stacked pack).  ``ClusterRouter.stats`` surfaces the same dict
-        per cell.
+        stacked pack), and ``fd_routes`` (the process's FD absorb
+        signatures traced per route, ``{"covariance": n, "chain": n}``;
+        see ``repro.core.fd.fd_route``).  ``ClusterRouter.stats``
+        surfaces the same dict per cell.
         """
         if tenant is None:
             # A fresh view over the obs registry, shaped exactly like the
@@ -1569,6 +1572,7 @@ class StreamingPipeline:
             c["pack_occupancy"] = c["packed_rows"] / max(
                 c["packed_rows"] + c["pad_rows"], 1
             )
+            c["fd_routes"] = jit_cache_stats()["routes"]
             return c
         t = self._tenant(tenant)
         return TenantStats(
